@@ -27,7 +27,6 @@ import time, json
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 M = N = K = 1024
 a = jnp.asarray(np.random.default_rng(0).standard_normal((M, K), np.float32))
@@ -35,9 +34,9 @@ b = jnp.asarray(np.random.default_rng(1).standard_normal((K, N), np.float32))
 out = {}
 for n in [1, 2, 4, 8]:
     mesh = Mesh(np.array(jax.devices()[:n]), ("cluster",))
-    f = jax.jit(shard_map(lambda at, bt: at @ bt, mesh=mesh,
-                          in_specs=(P("cluster", None), P(None, None)),
-                          out_specs=P("cluster", None)))
+    f = jax.jit(jax.shard_map(lambda at, bt: at @ bt, mesh=mesh,
+                              in_specs=(P("cluster", None), P(None, None)),
+                              out_specs=P("cluster", None)))
     r = f(a, b); r.block_until_ready()
     t0 = time.perf_counter()
     for _ in range(10):

@@ -28,7 +28,7 @@ from repro.runtime import (
 )
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b")
     ap.add_argument("--requests", type=int, default=8)
@@ -39,7 +39,7 @@ def main():
                     help="use the Pallas paged-attention kernels "
                          "(interpret mode on CPU; slower but exercises them)")
     ap.add_argument("--no-prefix-cache", action="store_true")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch).smoke()
     params = M.init_params(cfg, jax.random.PRNGKey(0))

@@ -67,19 +67,21 @@ for name in ARCHS:
 # the streaming serving example end-to-end: exercises the unified
 # generation API (EngineConfig / SamplingParams / generate() deltas) the
 # way a user would — it asserts internally that the streamed deltas
-# concatenate to the final results
+# concatenate to the final results.  It runs in this process: a child
+# started after this process has touched JAX could not get the chip.
 try:
-    import subprocess
+    import contextlib
+    import importlib.util
+    import io
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ,
-               PYTHONPATH=os.path.join(repo, "src") + os.pathsep
-               + os.environ.get("PYTHONPATH", ""))
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "examples", "serve_paged.py"),
-         "--requests", "4"],
-        capture_output=True, text=True, env=env, cwd=repo, timeout=900)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "served 5 requests" in r.stdout, r.stdout
+    spec = importlib.util.spec_from_file_location(
+        "serve_paged", os.path.join(repo, "examples", "serve_paged.py"))
+    serve_paged = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(serve_paged)
+    out = io.StringIO()
+    with contextlib.redirect_stdout(out):
+        serve_paged.main(["--requests", "4"])
+    assert "served 5 requests" in out.getvalue(), out.getvalue()
     print("OK   examples/serve_paged.py (streaming API demo)")
 except Exception as e:
     print(f"FAIL serve_paged example: {e}")

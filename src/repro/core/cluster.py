@@ -47,16 +47,14 @@ def cluster_parallel_matmul(mesh: Mesh, a: jax.Array, b: jax.Array,
     computes its row block of C, and writes it back — with `shard_map`, the
     per-cluster body is literally the single-cluster program.
     """
-    from jax.experimental.shard_map import shard_map
-
     per_cluster_fn = per_cluster_fn or (lambda at, bt: at @ bt)
 
     def body(at, bt):
         return per_cluster_fn(at, bt)
 
-    f = shard_map(body, mesh=mesh,
-                  in_specs=(P("cluster", None), P(None, None)),
-                  out_specs=P("cluster", None))
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P("cluster", None), P(None, None)),
+                      out_specs=P("cluster", None))
     return f(a, b)
 
 

@@ -16,7 +16,8 @@ One kernel body serves two entry points:
     KV pages (``pages_per_step``) with a single online-softmax rescale plus
     the causal in-chunk mask (the chunk's own K/V are pool-resident by the
     time the kernel runs, so one mask covers both history and in-chunk
-    causality).
+    causality).  q enters laid out per KV head, ``(B, Kv, C*G, hd)``, and
+    the step loops over the KV heads, so every product is a 2-D dot.
 
 ``paged_decode_fwd``
     One query token per request — the C=1 special case of the above (with
@@ -90,7 +91,7 @@ def paged_decode_fwd(q: jax.Array, kv_pages: jax.Array,
 
 def _prefill_kernel(bt_ref, cnt_ref, len_ref, start_ref, q_ref, *refs,
                     scale: float, page_size: int, g_pages: int, groups: int,
-                    quant: bool):
+                    kv_heads: int, quant: bool):
     kv_refs = refs[:g_pages]
     rest = refs[g_pages:]
     sc_refs = rest[:g_pages] if quant else ()
@@ -109,41 +110,45 @@ def _prefill_kernel(bt_ref, cnt_ref, len_ref, start_ref, q_ref, *refs,
 
     @pl.when(j * g_pages < npages)
     def _accumulate():
-        q = q_ref[0]                                   # (C, H, hd)
-        if quant:
-            # int8 pool: dequantize inside the fetch — one f32 scale per
-            # (page, K/V, kv-head) broadcast over page slots and head dim.
-            k = jnp.concatenate(
-                [r[0, 0].astype(jnp.float32) * s[0, 0][None, :, None]
-                 for r, s in zip(kv_refs, sc_refs)], axis=0)
-            v = jnp.concatenate(
-                [r[0, 1].astype(jnp.float32) * s[0, 1][None, :, None]
-                 for r, s in zip(kv_refs, sc_refs)], axis=0)
-        else:
-            k = jnp.concatenate([r[0, 0] for r in kv_refs], axis=0)
-            v = jnp.concatenate([r[0, 1] for r in kv_refs], axis=0)
-        C, _, hd = q.shape
-        Kv = k.shape[1]
-        qg = q.reshape(C, Kv, groups, hd)
-        s = jnp.einsum("ckgh,pkh->ckgp", qg.astype(jnp.float32),
-                       k.astype(jnp.float32)) * scale  # (C,Kv,G,G*page)
-        tok = j * g_pages * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 3)
-        qpos = start_ref[b] + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        # q rows are (chunk token c, group member g) pairs of one kv head,
+        # row r = c * groups + g, so the query position is start + r // G;
+        # the causal test tok <= start + r // G is rewritten without the
+        # division as (tok - start) * G <= r
+        R = q_ref.shape[2]
+        T = g_pages * page_size
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
+        tok = j * T + jax.lax.broadcasted_iota(jnp.int32, (R, T), 1)
         # one mask covers pool history AND in-chunk causality: the chunk's
         # own K/V are already pool-resident at positions start..start+C-1
-        mask = (tok < len_ref[b]) & (tok <= qpos)
-        s = jnp.where(mask, s, NEG_INF)
+        mask = (tok < len_ref[b]) & ((tok - start_ref[b]) * groups <= row)
 
-        m_prev, l_prev = m_ref[...], l_ref[...]        # (C,Kv,G,1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=3, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=3, keepdims=True)
-        ctx = jnp.einsum("ckgp,pkh->ckgh", p, v.astype(jnp.float32))
-        acc_ref[...] = acc_ref[...] * alpha + ctx
-        m_ref[...] = m_new
+        def page_rows(kv: int, h: int):
+            # (T, hd) rows of kv head h: one strided read per fetched page;
+            # an int8 page is dequantized by its (page, K/V, head) scale
+            parts = []
+            for gi, r in enumerate(kv_refs):
+                x = r[0, kv, :, h, :].astype(jnp.float32)
+                if quant:
+                    x = x * sc_refs[gi][0, kv, h]
+                parts.append(x)
+            return jnp.concatenate(parts, axis=0)
+
+        # a static loop of 2-D dots per kv head: Mosaic lowers these where
+        # a 4-D (C, Kv, G, hd) reshape of q has no supported layout
+        for h in range(kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)            # (R, hd)
+            s = jax.lax.dot_general(
+                q, page_rows(0, h), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (R, T)
+            s = jnp.where(mask, s, NEG_INF)
+            m_prev, l_prev = m_ref[h], l_ref[h]             # (R, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                p, page_rows(1, h), preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     last_step = (jnp.maximum(npages, 1) + g_pages - 1) // g_pages - 1
 
@@ -151,8 +156,7 @@ def _prefill_kernel(bt_ref, cnt_ref, len_ref, start_ref, q_ref, *refs,
     def _flush():
         lsum = l_ref[...]
         safe = jnp.where(lsum == 0.0, 1.0, lsum)
-        out = acc_ref[...] / safe                      # (C,Kv,G,hd)
-        o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)  # (Kv, R, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("pages_per_step", "interpret",
@@ -182,8 +186,13 @@ def paged_prefill_fwd(q: jax.Array, kv_pages: jax.Array,
     g = max(1, min(pages_per_step, n_pages))
     n_steps = _cdiv(n_pages, g)
     groups = H // Kv
+    R = C * groups
     sc = scale if scale is not None else 1.0 / math.sqrt(hd)
     quant = kv_scales is not None
+    # q per kv head: (B, Kv, C*G, hd), row c*G + g holds query head k*G + g
+    # of chunk token c (the kv-major GQA head layout)
+    qh = q.reshape(B, C, Kv, groups, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, Kv, R, hd)
 
     def page_imap(gi):
         def imap(b, j, bt, cnt, ln, st):
@@ -201,9 +210,9 @@ def paged_prefill_fwd(q: jax.Array, kv_pages: jax.Array,
         return pl.BlockSpec((1, 2, Kv),
                             lambda b, j, *a, _im=im: (_im(b, j, *a), 0, 0))
 
-    in_specs = ([pl.BlockSpec((1, C, H, hd), lambda b, j, *_: (b, 0, 0, 0))] +
-                [kv_spec(gi) for gi in range(g)])
-    operands = [q] + [kv_pages] * g
+    q_spec = pl.BlockSpec((1, Kv, R, hd), lambda b, j, *_: (b, 0, 0, 0))
+    in_specs = [q_spec] + [kv_spec(gi) for gi in range(g)]
+    operands = [qh] + [kv_pages] * g
     if quant:
         in_specs += [sc_spec(gi) for gi in range(g)]
         operands += [kv_scales] * g
@@ -212,17 +221,20 @@ def paged_prefill_fwd(q: jax.Array, kv_pages: jax.Array,
         num_scalar_prefetch=4,
         grid=(B, n_steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, C, H, hd), lambda b, j, *_: (b, 0, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((C, Kv, groups, 1), jnp.float32),
-            pltpu.VMEM((C, Kv, groups, 1), jnp.float32),
-            pltpu.VMEM((C, Kv, groups, hd), jnp.float32),
+            pltpu.VMEM((Kv, R, 1), jnp.float32),
+            pltpu.VMEM((Kv, R, 1), jnp.float32),
+            pltpu.VMEM((Kv, R, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=sc, page_size=page,
-                          g_pages=g, groups=groups, quant=quant),
+                          g_pages=g, groups=groups, kv_heads=Kv,
+                          quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Kv, R, hd), q.dtype),
         interpret=interpret,
     )(block_table, page_counts, lengths, q_start, *operands)
+    return out.reshape(B, Kv, C, groups, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, C, H, hd)

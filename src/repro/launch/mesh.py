@@ -11,7 +11,7 @@ import dataclasses
 import os
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 #: Recipe for getting C virtual devices on a CPU host (must be set before
 #: the first jax import; see README "Scaling across clusters").
@@ -54,7 +54,10 @@ def make_serving_mesh(clusters: int = 1, heads: int = 1) -> ClusterMesh:
             f"mesh {clusters}x{heads} needs {clusters * heads} devices, "
             f"only {n} visible (on CPU, relaunch with {HOST_DEVICE_RECIPE}; "
             f"XLA_FLAGS now: {os.environ.get('XLA_FLAGS', '<unset>')!r})")
-    mesh = jax.make_mesh((clusters, heads), ClusterMesh.AXIS_NAMES)
+    # Auto axes: the engine's eager per-lane updates of cluster-sharded
+    # state need no out_sharding (jax.make_mesh defaults to Explicit)
+    mesh = jax.make_mesh((clusters, heads), ClusterMesh.AXIS_NAMES,
+                         axis_types=(AxisType.Auto,) * 2)
     return ClusterMesh(mesh=mesh, clusters=clusters, heads=heads)
 
 

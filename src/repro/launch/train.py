@@ -13,6 +13,7 @@ import tempfile
 
 from repro.configs import get_config, SHAPES, smoke_shape
 from repro.data import MarkovChainData, SyntheticLMData
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import AdamWConfig
 from repro.runtime import Trainer, TrainerConfig
 
@@ -33,6 +34,7 @@ def main():
     ap.add_argument("--num-hosts", type=int, default=1)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

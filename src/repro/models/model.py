@@ -135,8 +135,11 @@ def build_params(f: ParamFactory, cfg: ArchConfig):
     return p
 
 
-def init_params(cfg: ArchConfig, rng: jax.Array):
-    f = ParamFactory("init", _dtype(cfg), rng)
+def init_params(cfg: ArchConfig, rng: jax.Array, sharding=None):
+    """Random parameters from ``rng``; ``sharding`` (a ``ParamFactory``
+    placement, e.g. ``parallel.sharding.head_param_sharding``) draws each
+    leaf directly into its target devices."""
+    f = ParamFactory("init", _dtype(cfg), rng, sharding)
     return build_params(f, cfg)
 
 

@@ -128,7 +128,6 @@ def _combine_local(y, meta, T, k, d):
 
 def _moe_shardmap(cfg: ArchConfig, p, x: jax.Array, capacity, dropless
                   ) -> Optional[jax.Array]:
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = current_mesh()
@@ -179,12 +178,12 @@ def _moe_shardmap(cfg: ArchConfig, p, x: jax.Array, capacity, dropless
         return out.reshape(Bl, S, d)
 
     dp_spec = dp_axes if len(dp_axes) > 1 else (dp_axes[0] if dp_axes else None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_spec, None, None), P(None, None),
                   P(ep, None, None), P(ep, None, None), P(ep, None, None)),
         out_specs=P(dp_spec, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 

@@ -43,7 +43,6 @@ import os
 from typing import List, Optional
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding
 import jax.numpy as jnp
 
@@ -112,38 +111,34 @@ class ShardedPagedServer(PagedServer):
         # the fused slab, re-laid-out: C contiguous (num_pages + 1) blocks
         # (trash page per cluster), pages sharded over `cluster`, kv heads
         # over `head`; lane state (incl. the sampling-policy rows) shards
-        # its batch dim over `cluster`
+        # its batch dim over `cluster`.  Every array is created in its
+        # sharding: no device ever holds the whole slab.
         cfg, C = self.cfg, self.clusters
         L_, kv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim
         dt = jnp.int8 if self.quant_kv else jnp.dtype(cfg.param_dtype)
         specs = cluster_engine_specs(self.params)
         mesh_ = self.cmesh.mesh
         ns = functools.partial(NamedSharding, mesh_)
-        self.kv_pages = jax.device_put(
-            jnp.zeros((L_, C * (num_pages + 1), 2, self.page_size, kv, hd),
-                      dt), ns(specs["kv"]))
+        self.kv_pages = jnp.zeros(
+            (L_, C * (num_pages + 1), 2, self.page_size, kv, hd), dt,
+            device=ns(specs["kv"]))
         # per-page dequant scales for the int8 KV mode; allocated in both
         # modes so the step signatures stay uniform (bf16 jit DCEs it)
-        self.kv_scales = jax.device_put(
-            jnp.zeros((L_, C * (num_pages + 1), 2, kv), jnp.float32),
-            ns(specs["kv_scales"]))
+        self.kv_scales = jnp.zeros((L_, C * (num_pages + 1), 2, kv),
+                                   jnp.float32, device=ns(specs["kv_scales"]))
         B = self.max_lanes
-        self.bt_dev = jax.device_put(
-            jnp.zeros((B, self.max_pages), jnp.int32), ns(specs["lane2"]))
-        self.len_dev = jax.device_put(jnp.zeros((B,), jnp.int32),
-                                      ns(specs["lane"]))
-        self.active_dev = jax.device_put(jnp.zeros((B,), jnp.int32),
-                                         ns(specs["lane"]))
-        self.last_tok = jax.device_put(jnp.zeros((B,), jnp.int32),
-                                       ns(specs["lane"]))
-        self.seed_dev = jax.device_put(jnp.zeros((B,), jnp.uint32),
-                                       ns(specs["lane"]))
-        self.temp_dev = jax.device_put(jnp.zeros((B,), jnp.float32),
-                                       ns(specs["lane"]))
-        self.topk_dev = jax.device_put(jnp.zeros((B,), jnp.int32),
-                                       ns(specs["lane"]))
-        self.topp_dev = jax.device_put(jnp.ones((B,), jnp.float32),
-                                       ns(specs["lane"]))
+        lane = ns(specs["lane"])
+        self.bt_dev = jnp.zeros((B, self.max_pages), jnp.int32,
+                                device=ns(specs["lane2"]))
+        self.len_dev = jnp.zeros((B,), jnp.int32, device=lane)
+        self.active_dev = jnp.zeros((B,), jnp.int32, device=lane)
+        self.last_tok = jnp.zeros((B,), jnp.int32, device=lane)
+        self.seed_dev = jnp.zeros((B,), jnp.uint32, device=lane)
+        self.temp_dev = jnp.zeros((B,), jnp.float32, device=lane)
+        self.topk_dev = jnp.zeros((B,), jnp.int32, device=lane)
+        self.topp_dev = jnp.ones((B,), jnp.float32, device=lane)
+        # a no-op for parameters already drawn into these shardings
+        # (launch.serve.build_engine); others move here once
         self.params = jax.tree.map(
             lambda x, s: jax.device_put(x, ns(s)), self.params,
             specs["params"])
@@ -166,9 +161,10 @@ class ShardedPagedServer(PagedServer):
                     step_fn, cfg, self.use_kernel, pages_per_step, itp,
                     num_pages, axis_name="head", quant=self.quant_kv,
                     sample=s)
-                return jax.jit(shard_map(body, mesh=mesh_,
-                                         in_specs=in_specs, out_specs=outs,
-                                         check_rep=False))
+                return jax.jit(jax.shard_map(body, mesh=mesh_,
+                                             in_specs=in_specs,
+                                             out_specs=outs,
+                                             check_vma=False))
             return {s: one(s) for s in (False, True)}
 
         self._chunk_step = mk(

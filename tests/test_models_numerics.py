@@ -171,3 +171,25 @@ def test_router_load_counts(rng):
     x = jax.random.normal(rng, (2, 16, cfg.d_model), jnp.float32)
     load = MOE.router_load(cfg, p, x)
     assert int(load.sum()) == 2 * 16 * cfg.moe_top_k
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_init_params_bit_identical_to_eager_draw(dtype, monkeypatch):
+    """The jitted per-leaf draw (which keeps the f32 draw off the device)
+    gives the values of the eager ``normal(...) * std`` cast, bit for bit,
+    so recorded outputs do not move."""
+    from repro.parallel import sharding as SH
+    cfg = dataclasses.replace(get_config("yi-6b").smoke(), param_dtype=dtype)
+    key = jax.random.PRNGKey(3)
+    jitted = M.init_params(cfg, key)
+    monkeypatch.setattr(
+        SH, "_normal_leaf", lambda k, shape, std, dt, where: (
+            jax.random.normal(k, shape, jnp.float32) * std).astype(dt))
+    eager = M.init_params(cfg, key)
+
+    def same(a, b):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    jax.tree.map(same, jitted, eager)
+    assert jitted["layers"]["attn"]["wq"].dtype == jnp.dtype(dtype)

@@ -117,19 +117,40 @@ def test_temperature_zero_is_greedy_regardless_of_seed(cfg, params):
         assert out == base, f"temperature=0 not greedy (seed={seed})"
 
 
+class _ScriptedDrafter:
+    """Proposes the recorded greedy continuation of whichever stream the
+    context extends, with the last draft token made wrong: acceptance and
+    rollback then both happen by construction, whatever the weights."""
+
+    def __init__(self, streams, vocab):
+        self.streams, self.vocab = streams, vocab
+
+    def propose(self, ctx, k):
+        for s in self.streams:
+            if s[:len(ctx)] == list(ctx):
+                drafts = s[len(ctx):len(ctx) + k]
+                if len(drafts) > 1:
+                    drafts[-1] = (drafts[-1] + 1) % self.vocab
+                return drafts
+        return []
+
+
 def test_temperature_zero_greedy_with_speculation_active(cfg, params):
     """Acceptance criterion: temperature=0 output is byte-identical to
     the pre-redesign greedy decode with speculation still engaged."""
-    rng = np.random.default_rng(5)
-    pat = rng.integers(1, cfg.vocab_size, size=4).tolist()
-    prompts = [pat * 3, pat * 3]        # repetitive: the drafter accepts
+    prompts = _prompts(cfg.vocab_size, n=2)
     base, _ = _serve(cfg, params, prompts,
                      lambda rid: SamplingParams(max_new=12))
+    drafter = _ScriptedDrafter(
+        [list(p) + list(base[rid]) for rid, p in enumerate(prompts)],
+        cfg.vocab_size)
     out, srv = _serve(cfg, params, prompts,
                       lambda rid: SamplingParams(temperature=0.0, seed=3,
-                                                 max_new=12), spec_k=4)
+                                                 max_new=12), spec_k=4,
+                      drafter=drafter)
     assert out == base
     assert srv.spec_accepted > 0, "speculation never engaged"
+    assert srv.spec_rejected > 0, "no draft was ever rolled back"
 
 
 def test_top_k_one_is_greedy_at_any_temperature(cfg, params):

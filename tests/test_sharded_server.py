@@ -189,6 +189,23 @@ _MULTI_CLUSTER_SCRIPT = textwrap.dedent("""
     assert sout == sbase, "sampled lane diverged across the mesh"
     assert all(sout[r] == base[r] for r in (0, 2, 3)), \\
         "a greedy lane changed because another lane sampled"
+    # build_engine draws the parameters straight into the 2x2 mesh's
+    # shardings, with the values of the plain draw from the same seed
+    from jax.sharding import NamedSharding
+    from repro.launch.serve import build_engine
+    from repro.parallel.sharding import head_param_pspecs
+    srv = build_engine(cfg, EngineConfig(
+        cache=CacheConfig(num_pages=16, page_size=4, max_pages_per_seq=8),
+        max_lanes=2, clusters=2, heads=2), seed=0)
+
+    def drawn_in_place(a, b, spec):
+        assert (a == b).all()
+        assert b.sharding.is_equivalent_to(
+            NamedSharding(srv.cmesh.mesh, spec), b.ndim)
+    jax.tree.map(drawn_in_place, params, srv.params,
+                 head_param_pspecs(params))
+    wq = srv.params["layers"]["attn"]["wq"]
+    assert wq.addressable_shards[0].data.shape[-2] == cfg.num_heads // 2
     print("MULTI_CLUSTER_OK")
 """)
 
@@ -196,7 +213,8 @@ _MULTI_CLUSTER_SCRIPT = textwrap.dedent("""
 def test_multi_cluster_parity_subprocess():
     """2- and 4-cluster (and 2x2 head-sharded) engines match the unsharded
     engine token-for-token, including across a forced preemption and with
-    a sampled lane in the mix — run in a subprocess because the virtual
+    a sampled lane in the mix, and ``build_engine`` draws parameters into
+    the 2x2 mesh's shardings — run in a subprocess because the virtual
     device count must be fixed before the first jax import."""
     env = dict(os.environ,
                PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH",
