@@ -95,7 +95,8 @@ def main(argv=None):
     print(f"# backing store: {srv.backing.bytes_out} B out, "
           f"{srv.backing.bytes_in} B in")
     events = layer1_decode(srv.tracer.drain())
-    print(f"\n# {len(events)} events; TLB transactions (first 10):")
+    print(f"\n# {len(events)} events ({srv.tracer.dropped} dropped); "
+          "TLB transactions (first 10):")
     for tx in layer2_tlb_transactions(events)[:10]:
         print(tx)
     print("\n# request lifecycles (admit/preempt/swap_in/finish):")

@@ -95,13 +95,12 @@ def layer2_latency(events: Iterable[Event]) -> Dict:
     ``REQUEST_ARRIVE`` (rid, queue depth) marks a request entering the
     engine queue; ``REQUEST_ADMIT`` (rid, lane) its first/each placement;
     ``REQUEST_FINISH`` (rid, tokens) its exit.  Timestamps are the host
-    tracer's logical clock (event counts, not seconds — the engine's
-    *wall* latency lives on the injected Clock and is reported by
-    ``runtime.frontdoor.latency_report``), so what this view exposes is
-    the *ordering* structure: how much scheduler activity elapsed between
-    arrival, first admission and finish.  Returns per-request
-    ``queue_delay`` (arrive -> first admit), ``service`` (first admit ->
-    finish) and ``e2e`` plus aggregate means/maxima."""
+    tracer's nanoseconds (``time.perf_counter_ns``, strictly increasing),
+    so the spans are host wall time — not the injected Clock, whose
+    latency ``runtime.frontdoor.latency_report`` reports.  Returns
+    per-request ``queue_delay`` (arrive -> first admit), ``service``
+    (first admit -> finish) and ``e2e`` in ns, plus aggregate
+    means/maxima."""
     per: Dict[int, Dict] = {}
     for e in events:
         if e.etype == EventType.REQUEST_ARRIVE:
@@ -144,9 +143,9 @@ def layer2_calibration(events: Iterable[Event],
                        iter_time_s: Optional[float] = None) -> Dict:
     """Planner calibration: per-iteration service structure from a trace.
 
-    ``layer2_latency`` reports queue/service spans in *logical event
-    counts*, which depend on how chatty the tracer was.  The capacity
-    planner needs those spans in *engine iterations* — the unit its
+    ``layer2_latency`` reports queue/service spans in host nanoseconds,
+    which depend on the machine.  The capacity planner needs those spans
+    in *engine iterations* — the unit its
     simulator steps in and the unit ``iter_time_s`` prices.  The engine
     emits exactly one ``D2H`` token-pull event per iteration, so D2H
     events serve as iteration ticks: this walks the stream once,

@@ -2,37 +2,43 @@
 
 HERO's tracers are hardware blocks that (1) never perturb execution, (2) are
 cycle-accurate, (3) use buffers economically, and (4) need no application
-changes.  The JAX adaptation keeps all four properties:
+changes.  The JAX adaptation has two parts, both cheap enough to stay on,
+and each fact lives in one of them:
 
-  * device-side events are recorded as pure array writes into a fixed-size
-    ring buffer *carried through the jitted step* — no host callback in the
-    hot path (non-intrusive);
-  * the logical clock is a monotonically increasing counter carried with the
-    buffer (all tracers share it, like HERO's common gated clock);
-  * when the buffer fills, recording saturates; the host drains between steps
-    (the step boundary is the analogue of HERO's clock-freeze-and-drain);
-  * host-side events (offload begin/end, RAB activity) are recorded into the
-    same stream with the same schema, so the analyzer sees one timeline.
+  * host events (offload begin/end, RAB activity, scheduler decisions) own
+    *what* happened and in which order: which request arrived, was placed
+    or preempted, which page was allocated, copied or demoted, each token
+    pull.  They are rows of one schema, stamped with
+    ``time.perf_counter_ns()`` kept strictly increasing, into a buffer
+    that saturates at ``capacity`` and counts what it dropped (HERO's
+    buffers freeze when full; the analyzer drains between steps, and
+    ``drain`` warns with ``TruncatedTrace`` when the stream lost events);
+  * spans (``TraceBuffer.span``) own *how long* each engine host phase
+    took, against the device: with a ``jax.profiler`` session they land in
+    its trace as ``engine.<name>``, on the same clock as the device's
+    operations, so an idle gap on the device reads as the host phase that
+    holds it; without one a span costs about a microsecond.  A span's
+    args are only the request id, which joins a request's spans, and the
+    work a dispatch hands the device (``kind``, ``tokens``, ``slots``);
+    queue depths, lanes, prefix hits and pages stay with the events.  The
+    events' own stamps time the stream where no profiler runs.
 
-Event schema (int64 x 5): (timestamp, tracer_id, event_type, arg0, arg1).
+Event schema (int64 x 5): (timestamp_ns, tracer_id, event_type, arg0, arg1).
 """
 from __future__ import annotations
 
 import enum
-from typing import Dict, List, Optional, Tuple
+import time
+import warnings
+from typing import List, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 
 class EventType(enum.IntEnum):
-    # device-side
-    STEP_BEGIN = 1
-    STEP_END = 2
-    MEM_READ = 3
-    MEM_WRITE = 4
-    SYNC = 5
+    # trainer
+    SYNC = 5               # straggler step flagged: (step, 1)
     # RAB / VMM protocol (§3.4)
     TLB_L1_HIT = 10
     TLB_L2_HIT = 11
@@ -99,70 +105,52 @@ class EventType(enum.IntEnum):
 HOST_TRACER_ID = 255
 
 
+class TruncatedTrace(RuntimeWarning):
+    """A drained stream lacks the events recorded while the buffer was
+    full: views that need every event (per-request latency, calibration,
+    fault containment) would read it wrong."""
+
+
 class TraceBuffer:
-    """Fixed-capacity event buffer; device part is a pytree."""
+    """Host event buffer of at most ``capacity`` rows; ``dropped`` counts
+    the events recorded while it was full."""
 
     def __init__(self, capacity: int = 4096):
         self.capacity = capacity
         self.host_events: List[Tuple[int, int, int, int, int]] = []
-        self._host_clock = 0
+        self._last_ns = 0
         self.dropped = 0
+        self._dropped_drained = 0
 
-    # ------------------------------------------------------------- device --
-    def device_init(self) -> Dict[str, jax.Array]:
-        return {
-            "events": jnp.zeros((self.capacity, 5), jnp.int32),
-            "count": jnp.zeros((), jnp.int32),
-            "clock": jnp.zeros((), jnp.int32),
-        }
-
-    @staticmethod
-    def record(dev: Dict[str, jax.Array], tracer_id: int, etype: int,
-               a0=0, a1=0) -> Dict[str, jax.Array]:
-        """Pure-functional in-step event record (saturating)."""
-        cap = dev["events"].shape[0]
-        idx = jnp.minimum(dev["count"], cap - 1)
-        ev = jnp.stack([dev["clock"],
-                        jnp.asarray(tracer_id, jnp.int32),
-                        jnp.asarray(etype, jnp.int32),
-                        jnp.asarray(a0, jnp.int32),
-                        jnp.asarray(a1, jnp.int32)])
-        events = jax.lax.dynamic_update_slice(dev["events"], ev[None, :],
-                                              (idx, 0))
-        return {"events": events, "count": dev["count"] + 1,
-                "clock": dev["clock"] + 1}
-
-    @staticmethod
-    def tick(dev: Dict[str, jax.Array], n: int = 1) -> Dict[str, jax.Array]:
-        """Advance the logical clock without recording (models latency)."""
-        return dict(dev, clock=dev["clock"] + n)
-
-    # --------------------------------------------------------------- host --
     def record_host(self, etype: EventType, a0: int = 0, a1: int = 0):
-        self._host_clock += 1
+        if len(self.host_events) >= self.capacity:
+            self.dropped += 1
+            return
+        self._last_ns = max(time.perf_counter_ns(), self._last_ns + 1)
         self.host_events.append(
-            (self._host_clock, HOST_TRACER_ID, int(etype), int(a0), int(a1)))
+            (self._last_ns, HOST_TRACER_ID, int(etype), int(a0), int(a1)))
 
-    def drain(self, dev: Optional[Dict[str, jax.Array]] = None) -> np.ndarray:
-        """Freeze-and-drain: pull device events + host events, clear both.
+    @staticmethod
+    def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+        """A context manager naming a host phase ``engine.<name>`` in the
+        profiler's trace, with ``args`` (ints or short constant strings)
+        as its stats.  Build no strings per call and open none per page
+        or token."""
+        return jax.profiler.TraceAnnotation("engine." + name, **args)
 
-        Returns an (N,5) int64 array sorted by (source, timestamp); device
-        timestamps are kept in their own clock domain (tracer_id separates
-        domains, as HERO's per-logger streams do).
-        """
-        rows: List[np.ndarray] = []
-        if dev is not None:
-            n = int(dev["count"])
-            cap = dev["events"].shape[0]
-            if n > cap:
-                self.dropped += n - cap
-                n = cap
-            if n:
-                rows.append(np.asarray(dev["events"][:n], np.int64))
-        if self.host_events:
-            rows.append(np.asarray(self.host_events, np.int64))
-            self.host_events = []
-        if not rows:
+    def drain(self) -> np.ndarray:
+        """Freeze-and-drain: the host events as an (N,5) int64 array in
+        time order, and an empty buffer.  Warns ``TruncatedTrace`` when
+        events were dropped since the last drain."""
+        lost = self.dropped - self._dropped_drained
+        if lost:
+            self._dropped_drained = self.dropped
+            warnings.warn(
+                f"trace truncated: {lost} host events dropped at capacity "
+                f"{self.capacity}; pass a larger TraceBuffer(capacity=)",
+                TruncatedTrace, stacklevel=2)
+        if not self.host_events:
             return np.zeros((0, 5), np.int64)
-        out = np.concatenate(rows, axis=0)
-        return out[np.lexsort((out[:, 0], out[:, 1]))]
+        out = np.asarray(self.host_events, np.int64)
+        self.host_events = []
+        return out

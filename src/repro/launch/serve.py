@@ -106,7 +106,7 @@ def main(argv: Optional[list] = None):
               f"accepted={srv.spec_accepted} rejected={srv.spec_rejected} "
               f"iters/token={srv.iterations / max(gen, 1):.2f}")
     events = layer1_decode(srv.tracer.drain())
-    print(f"{len(events)} trace events; "
+    print(f"{len(events)} trace events ({srv.tracer.dropped} dropped); "
           f"{len(layer2_tlb_transactions(events))} TLB transactions")
 
 

@@ -210,6 +210,13 @@ def _unpack_kv_page(blob: np.ndarray, page_shape: tuple,
     return pages, scales
 
 
+def named_step(fn, kind: str, sample: bool):
+    """Name a step program ``paged_<kind>_step_<greedy|sampled>``: the
+    module lowers as ``jit_<name>``, which the device trace shows."""
+    fn.__name__ = f"paged_{kind}_step_{'sampled' if sample else 'greedy'}"
+    return fn
+
+
 class PagedServer:
     def __init__(self, cfg: ArchConfig, params,
                  engine: Optional[EngineConfig] = None, *,
@@ -344,16 +351,16 @@ class PagedServer:
         # sorts/softmax whose results a where() would discard), so the
         # historical greedy hot path pays nothing for the sampling API;
         # jit is lazy, so greedy-only workloads never compile the other
-        def mk(step_fn):
-            return {s: jax.jit(functools.partial(
+        def mk(step_fn, kind):
+            return {s: jax.jit(named_step(functools.partial(
                 step_fn, cfg, self.use_kernel, pages_per_step, itp,
-                num_pages, quant=self.quant_kv, sample=s))
+                num_pages, quant=self.quant_kv, sample=s), kind, s))
                 for s in (False, True)}
 
-        self._chunk_step = mk(_paged_chunk_step)
-        self._decode_step = mk(_paged_decode_step)
+        self._chunk_step = mk(_paged_chunk_step, "chunk")
+        self._decode_step = mk(_paged_decode_step, "decode")
         if self.spec_k:
-            self._spec_step = mk(_paged_spec_step)
+            self._spec_step = mk(_paged_spec_step, "spec")
         # device-resident engine state (HERO SVM: the scheduler and the
         # model share these without per-iteration re-uploads); the four
         # sampling-policy rows ride with the lane like lengths do
@@ -434,36 +441,38 @@ class PagedServer:
         if self._pages_needed(seq) + self._cow_budget(seq) > \
                 self._capacity_pages():
             raise ValueError("request exceeds KV pool capacity")
-        seq.arrival = self._arrival
-        self._arrival += 1
-        if req.deadline_iters is not None:
-            seq.deadline_iter = self.iterations + req.deadline_iters
-        if req.deadline_s is not None:
-            # bound on the injected clock, not raw time.monotonic(): under
-            # a VirtualClock the request times out at an exact, testable
-            # tick; under the wall clock behaviour is unchanged
-            seq.deadline_t = self.clock.now() + req.deadline_s
-        self.tracer.record_host(EventType.REQUEST_ARRIVE, seq.rid,
-                                len(self.queue))
-        if self.spec_k and sp.greedy:
-            # drafting is greedy-lane-only: verification is greedy argmax,
-            # so a sampled lane's drafts could never be parity-accepted
-            seq.spec_k_cur = self.spec_k
-        self.queue.append(seq)
-        if self.max_queue_depth and len(self.queue) > self.max_queue_depth:
-            # admission-time load shedding: rather than admit work that
-            # will thrash the pool, reject the lowest-priority waiter
-            # (youngest within a class — so on a priority tie the
-            # newcomer itself is turned away).  Preemption re-queues
-            # bypass this: a victim already holds parked state and must
-            # be allowed back.
-            victim = min(self.queue, key=lambda r: (r.priority, -r.arrival))
-            self.shed_count += 1
-            self.tracer.record_host(EventType.REQUEST_SHED, victim.rid,
+        with self.tracer.span("submit", rid=req.rid):
+            seq.arrival = self._arrival
+            self._arrival += 1
+            if req.deadline_iters is not None:
+                seq.deadline_iter = self.iterations + req.deadline_iters
+            if req.deadline_s is not None:
+                # bound on the injected clock, not raw time.monotonic(): under
+                # a VirtualClock the request times out at an exact, testable
+                # tick; under the wall clock behaviour is unchanged
+                seq.deadline_t = self.clock.now() + req.deadline_s
+            self.tracer.record_host(EventType.REQUEST_ARRIVE, seq.rid,
                                     len(self.queue))
-            self._terminate(victim, FINISH_SHED, "shed",
-                            diag="queue depth exceeded "
-                                 f"{self.max_queue_depth}")
+            if self.spec_k and sp.greedy:
+                # drafting is greedy-lane-only: verification is greedy argmax,
+                # so a sampled lane's drafts could never be parity-accepted
+                seq.spec_k_cur = self.spec_k
+            self.queue.append(seq)
+            if self.max_queue_depth and len(self.queue) > self.max_queue_depth:
+                # admission-time load shedding: rather than admit work that
+                # will thrash the pool, reject the lowest-priority waiter
+                # (youngest within a class — so on a priority tie the
+                # newcomer itself is turned away).  Preemption re-queues
+                # bypass this: a victim already holds parked state and must
+                # be allowed back.
+                victim = min(self.queue,
+                             key=lambda r: (r.priority, -r.arrival))
+                self.shed_count += 1
+                self.tracer.record_host(EventType.REQUEST_SHED, victim.rid,
+                                        len(self.queue))
+                self._terminate(victim, FINISH_SHED, "shed",
+                                diag="queue depth exceeded "
+                                     f"{self.max_queue_depth}")
 
     def _pages_needed(self, req: SeqState) -> int:
         # every token the engine will *write* K/V for: the prompt plus all
@@ -569,10 +578,12 @@ class PagedServer:
                 victim = self._victim(head)
                 if victim is None:
                     break
-                self._preempt(victim)
+                with self.tracer.span("preempt", rid=victim.rid):
+                    self._preempt(victim)
                 continue                  # pool/lane state changed: re-plan
             self.queue.remove(head)
-            self._place(head, lane, plan)
+            with self.tracer.span("place", rid=head.rid):
+                self._place(head, lane, plan)
 
     def _resolve_spilled_hits(self, req: SeqState, plan: dict):
         """Pull every spilled hit's payload out of the tier store *before*
@@ -1247,111 +1258,128 @@ class PagedServer:
         Deltas accumulate on ``self._deltas`` (drained by ``generate()``
         after every step) rather than being cleared here, so events
         recorded *between* iterations — a ``preempt()`` or ``submit()``
-        from the caller's generate-loop body — still reach the stream."""
-        self._sweep_deadlines()
-        self._land_promotions()
-        self._admit()
-        self._land_promotions()     # zero-latency promotions land in-step
-        active = self._runnable()
-        if not active and (self.queue or self._promotions):
-            # nothing runs and every waiter is deferred (backing off) or
-            # gated on an in-flight promotion: park on the clock until the
-            # earliest retry/landing comes due, then re-try — otherwise
-            # run() would spin on an idle engine (and on a VirtualClock
-            # nobody else ever moves time forward)
-            waits = [pr["due"] for pr in self._promotions]
-            if self.queue:
-                nb = min(r.not_before for r in self.queue)
-                if nb > self.clock.now():
-                    waits.append(nb)
-            if waits:
-                self.clock.hold_until(min(waits))
+        from the caller's generate-loop body — still reach the stream.
+
+        Each part is a tracer span nested in ``engine.step``: ``sweep``,
+        ``admit`` (with ``place`` / ``preempt``), ``account``,
+        ``dispatch``, ``pull`` and ``emit`` (with ``first_token``)."""
+        with self.tracer.span("step"):
+            with self.tracer.span("sweep"):
+                self._sweep_deadlines()
                 self._land_promotions()
+            with self.tracer.span("admit"):
                 self._admit()
-                active = self._runnable()
-        if not active:
-            return bool(self.queue) or bool(self._promotions)
-        self.iterations += 1
-        t0 = self.clock.now()
+                self._land_promotions()     # zero-latency promotions land
+            active = self._runnable()
+            if not active and (self.queue or self._promotions):
+                # nothing runs and every waiter is deferred (backing off) or
+                # gated on an in-flight promotion: park on the clock until the
+                # earliest retry/landing comes due, then re-try — otherwise
+                # run() would spin on an idle engine (and on a VirtualClock
+                # nobody else ever moves time forward)
+                waits = [pr["due"] for pr in self._promotions]
+                if self.queue:
+                    nb = min(r.not_before for r in self.queue)
+                    if nb > self.clock.now():
+                        waits.append(nb)
+                if waits:
+                    self.clock.hold_until(min(waits))
+                    with self.tracer.span("admit"):
+                        self._land_promotions()
+                        self._admit()
+                    active = self._runnable()
+            if not active:
+                return bool(self.queue) or bool(self._promotions)
+            self.iterations += 1
+            t0 = self.clock.now()
 
-        if self._spec_wanted(active):
-            drafts, n_spec = self._propose(active)
-            if drafts is not None:
-                self._spec_iteration(active, drafts, n_spec)
+            if self._spec_wanted(active):
+                drafts, n_spec = self._propose(active)
+                if drafts is not None:
+                    self._spec_iteration(active, drafts, n_spec, t0)
+                    return True
+
+            B, C = self.max_lanes, self.chunk
+            n_new = np.zeros((B,), np.int32)
+            feed = np.zeros((B, C), np.int32)
+            use_last = np.zeros((B,), np.int32)
+            prefill = [(r.lane, len(r.prompt) - r.fed) for r in active
+                       if r.fed < len(r.prompt)]
+            decode_only = not prefill
+            alloc: dict = {}
+            if prefill:
+                # the interleave policy decides how many prompt tokens each
+                # prefill-phase lane feeds; decode lanes always advance one
+                alloc = dict(self.policy.plan(
+                    tuple(prefill), len(active) - len(prefill), C))
+                if len(prefill) == len(active) and \
+                        not any(alloc.get(ln, rem) for ln, rem in prefill):
+                    # a budget policy may starve every prefill lane in a mixed
+                    # batch, but an all-prefill iteration that feeds nothing
+                    # would never progress: force the oldest lane one chunk
+                    alloc[prefill[0][0]] = min(C, prefill[0][1])
+            for r in active:
+                i = r.lane
+                if r.fed < len(r.prompt):
+                    n = min(C, len(r.prompt) - r.fed)
+                    n = max(0, min(n, int(alloc.get(i, n))))
+                    if n:
+                        feed[i, :n] = r.prompt[r.fed:r.fed + n]
+                        n_new[i] = n
+                        self.prefill_tokens += n
+                else:
+                    n_new[i] = 1
+                    use_last[i] = 1     # token is device-resident; no upload
+
+            with self.tracer.span("account"):
+                self._account_appends(active, n_new)
+
+            smp = any(not r.sampling.greedy for r in active)
+            # slots: the positions the step program computes, B or B x C
+            with self.tracer.span(
+                    "dispatch", kind="decode" if decode_only else "chunk",
+                    tokens=int(n_new.sum()),
+                    slots=B if decode_only else B * C):
+                if decode_only:
+                    # sync-free: every input already lives on device
+                    self.last_tok, self.kv_pages, self.kv_scales, \
+                        self.len_dev = self._decode_step[smp](
+                            self.params, self.kv_pages, self.kv_scales,
+                            self.bt_dev, self.len_dev, self.active_dev,
+                            self.last_tok, self.seed_dev, self.temp_dev,
+                            self.topk_dev, self.topp_dev)
+                else:
+                    self._h2d(1)            # the prompt-chunk feed bundle
+                    self.last_tok, self.kv_pages, self.kv_scales, \
+                        self.len_dev = self._chunk_step[smp](
+                            self.params, self.kv_pages, self.kv_scales,
+                            self.bt_dev, self.len_dev, jnp.asarray(n_new),
+                            jnp.asarray(feed), self.last_tok,
+                            jnp.asarray(use_last), self.seed_dev,
+                            self.temp_dev, self.topk_dev, self.topp_dev)
+
+            with self.tracer.span("pull"):
+                tok = np.asarray(self.last_tok)     # one pull per iteration
+                self._d2h(1)
+
+            with self.tracer.span("emit"):
+                for r in list(active):
+                    i = r.lane
+                    reason = None
+                    kept: List[int] = []
+                    if r.fed < len(r.prompt):
+                        r.fed += int(n_new[i])
+                        if r.fed == len(r.prompt):
+                            with self.tracer.span("first_token", rid=r.rid):
+                                kept, reason = self._emit(r, [int(tok[i])])
+                    else:
+                        kept, reason = self._emit(r, [int(tok[i])])
+                    if kept or reason:
+                        self._delta(r.rid, kept, reason=reason)
+                    if reason:
+                        self._finish(r, reason)
                 self._post_iteration(self.clock.now() - t0)
-                return True
-
-        B, C = self.max_lanes, self.chunk
-        n_new = np.zeros((B,), np.int32)
-        feed = np.zeros((B, C), np.int32)
-        use_last = np.zeros((B,), np.int32)
-        prefill = [(r.lane, len(r.prompt) - r.fed) for r in active
-                   if r.fed < len(r.prompt)]
-        decode_only = not prefill
-        alloc: dict = {}
-        if prefill:
-            # the interleave policy decides how many prompt tokens each
-            # prefill-phase lane feeds; decode lanes always advance one
-            alloc = dict(self.policy.plan(
-                tuple(prefill), len(active) - len(prefill), C))
-            if len(prefill) == len(active) and \
-                    not any(alloc.get(ln, rem) for ln, rem in prefill):
-                # a budget policy may starve every prefill lane in a mixed
-                # batch, but an all-prefill iteration that feeds nothing
-                # would never progress: force the oldest lane one chunk
-                alloc[prefill[0][0]] = min(C, prefill[0][1])
-        for r in active:
-            i = r.lane
-            if r.fed < len(r.prompt):
-                n = min(C, len(r.prompt) - r.fed)
-                n = max(0, min(n, int(alloc.get(i, n))))
-                if n:
-                    feed[i, :n] = r.prompt[r.fed:r.fed + n]
-                    n_new[i] = n
-                    self.prefill_tokens += n
-            else:
-                n_new[i] = 1
-                use_last[i] = 1     # token is device-resident; no upload
-
-        self._account_appends(active, n_new)
-
-        smp = any(not r.sampling.greedy for r in active)
-        if decode_only:
-            # sync-free: every input already lives on device
-            self.last_tok, self.kv_pages, self.kv_scales, self.len_dev = \
-                self._decode_step[smp](
-                    self.params, self.kv_pages, self.kv_scales, self.bt_dev,
-                    self.len_dev, self.active_dev, self.last_tok,
-                    self.seed_dev, self.temp_dev, self.topk_dev,
-                    self.topp_dev)
-        else:
-            self._h2d(1)            # the prompt-chunk feed bundle
-            self.last_tok, self.kv_pages, self.kv_scales, self.len_dev = \
-                self._chunk_step[smp](
-                    self.params, self.kv_pages, self.kv_scales, self.bt_dev,
-                    self.len_dev, jnp.asarray(n_new), jnp.asarray(feed),
-                    self.last_tok, jnp.asarray(use_last), self.seed_dev,
-                    self.temp_dev, self.topk_dev, self.topp_dev)
-
-        tok = np.asarray(self.last_tok)     # one pull per iteration
-        self._d2h(1)
-
-        for r in list(active):
-            i = r.lane
-            reason = None
-            kept: List[int] = []
-            if r.fed < len(r.prompt):
-                r.fed += int(n_new[i])
-                if r.fed == len(r.prompt):
-                    kept, reason = self._emit(r, [int(tok[i])])
-            else:
-                kept, reason = self._emit(r, [int(tok[i])])
-            if kept or reason:
-                self._delta(r.rid, kept, reason=reason)
-            if reason:
-                self._finish(r, reason)
-        self._post_iteration(self.clock.now() - t0)
-        return True
+            return True
 
     def _post_iteration(self, dt: float):
         """Scheduler watchdog, run after every engine iteration.
@@ -1450,7 +1478,7 @@ class PagedServer:
         return (drafts, n_spec) if any_draft else (None, None)
 
     def _spec_iteration(self, active: List[SeqState], drafts: np.ndarray,
-                        n_spec: np.ndarray):
+                        n_spec: np.ndarray, t0: float):
         """One draft-verify-rollback engine iteration.
 
         The pool appends all K+1 candidate positions per lane (pages
@@ -1462,7 +1490,8 @@ class PagedServer:
         sampled token are updated inside the jitted step from the
         acceptance itself, so the only pull is the one verdict array.
         Sampled lanes participate with zero drafts: they advance exactly
-        one position-folded sampled token, unchanged from plain decode."""
+        one position-folded sampled token, unchanged from plain decode.
+        ``t0`` is the iteration's start on the engine clock."""
         self.spec_iterations += 1
         lens0 = {r.rid: self._pool(r).seq_len[r.rid] for r in active}
         n_new = np.zeros((self.max_lanes,), np.int32)
@@ -1473,49 +1502,56 @@ class PagedServer:
                 self.tracer.record_host(EventType.SPEC_PROPOSE, r.rid, k_i)
                 self.spec_proposed += k_i
                 r.spec_proposed += k_i
-        self._account_appends(active, n_new)
-
-        self._h2d(1)                # the draft feed bundle
-        smp = any(not r.sampling.greedy for r in active)
-        verdict, self.kv_pages, self.kv_scales, self.last_tok, \
-            self.len_dev = self._spec_step[smp](
-                self.params, self.kv_pages, self.kv_scales, self.bt_dev,
-                self.len_dev, self.active_dev, self.last_tok,
-                jnp.asarray(drafts), jnp.asarray(n_spec), self.seed_dev,
-                self.temp_dev, self.topk_dev, self.topp_dev)
-        v = np.asarray(verdict)     # one pull per iteration
-        self._d2h(1)
+        with self.tracer.span("account"):
+            self._account_appends(active, n_new)
 
         K = drafts.shape[1]
-        for r in list(active):
-            i = r.lane
-            k_i = int(n_spec[i])
-            a = int(v[i, K + 1])
-            emitted = [int(t) for t in drafts[i, :a]] + [int(v[i, a])]
-            freed = self._pool(r).trim(r.rid, lens0[r.rid] + a + 1)
-            kept, reason = self._emit(r, emitted)
-            self._delta(r.rid, kept, event="spec" if k_i else "token",
-                        data=a, reason=reason)
-            if k_i:
-                self.tracer.record_host(EventType.SPEC_ACCEPT, r.rid, a)
-                self.spec_accepted += a
-                r.spec_accepted += a
-                rej = k_i - a
-                if rej:
-                    self.spec_rejected += rej
-                    r.spec_rejected += rej
-                    self.tracer.record_host(EventType.SPEC_ROLLBACK,
-                                            r.rid, rej)
-                # adaptive depth: full acceptance earns a wider window,
-                # total rejection halves it (never below 1)
-                if a == k_i:
-                    r.spec_k_cur = min(self.spec_k, r.spec_k_cur + 1)
-                elif a == 0:
-                    r.spec_k_cur = max(1, r.spec_k_cur // 2)
-            if freed:
-                self._refresh_row(i, r)
-            if reason:
-                self._finish(r, reason)
+        smp = any(not r.sampling.greedy for r in active)
+        with self.tracer.span("dispatch", kind="spec",
+                              tokens=int(n_new.sum()),
+                              slots=self.max_lanes * (K + 1)):
+            self._h2d(1)                # the draft feed bundle
+            verdict, self.kv_pages, self.kv_scales, self.last_tok, \
+                self.len_dev = self._spec_step[smp](
+                    self.params, self.kv_pages, self.kv_scales, self.bt_dev,
+                    self.len_dev, self.active_dev, self.last_tok,
+                    jnp.asarray(drafts), jnp.asarray(n_spec), self.seed_dev,
+                    self.temp_dev, self.topk_dev, self.topp_dev)
+        with self.tracer.span("pull"):
+            v = np.asarray(verdict)     # one pull per iteration
+            self._d2h(1)
+
+        with self.tracer.span("emit"):
+            for r in list(active):
+                i = r.lane
+                k_i = int(n_spec[i])
+                a = int(v[i, K + 1])
+                emitted = [int(t) for t in drafts[i, :a]] + [int(v[i, a])]
+                freed = self._pool(r).trim(r.rid, lens0[r.rid] + a + 1)
+                kept, reason = self._emit(r, emitted)
+                self._delta(r.rid, kept, event="spec" if k_i else "token",
+                            data=a, reason=reason)
+                if k_i:
+                    self.tracer.record_host(EventType.SPEC_ACCEPT, r.rid, a)
+                    self.spec_accepted += a
+                    r.spec_accepted += a
+                    rej = k_i - a
+                    if rej:
+                        self.spec_rejected += rej
+                        r.spec_rejected += rej
+                        self.tracer.record_host(EventType.SPEC_ROLLBACK,
+                                                r.rid, rej)
+                    # adaptive depth: full acceptance earns a wider window,
+                    # total rejection halves it (never below 1)
+                    if a == k_i:
+                        r.spec_k_cur = min(self.spec_k, r.spec_k_cur + 1)
+                    elif a == 0:
+                        r.spec_k_cur = max(1, r.spec_k_cur // 2)
+                if freed:
+                    self._refresh_row(i, r)
+                if reason:
+                    self._finish(r, reason)
+            self._post_iteration(self.clock.now() - t0)
 
     # ---------------------------------------------------------- frontend --
     def poll_deltas(self) -> List[TokenDelta]:

@@ -56,7 +56,7 @@ from repro.parallel.sharding import cluster_engine_specs
 from repro.runtime.api import EngineConfig
 from repro.runtime.server import (
     PagedServer, SeqState, _paged_chunk_step, _paged_decode_step,
-    _paged_spec_step,
+    _paged_spec_step, named_step,
 )
 
 __all__ = ["ShardedPagedServer"]
@@ -155,12 +155,12 @@ class ShardedPagedServer(PagedServer):
         # the same two-variant dispatch as the unsharded engine (all-greedy
         # batches never trace the sampler), each variant the shard_map'd
         # single-cluster program; jit is lazy, so only used variants compile
-        def mk(step_fn, in_specs, outs):
+        def mk(step_fn, kind, in_specs, outs):
             def one(s):
-                body = functools.partial(
+                body = named_step(functools.partial(
                     step_fn, cfg, self.use_kernel, pages_per_step, itp,
                     num_pages, axis_name="head", quant=self.quant_kv,
-                    sample=s)
+                    sample=s), kind, s)
                 return jax.jit(jax.shard_map(body, mesh=mesh_,
                                              in_specs=in_specs,
                                              out_specs=outs,
@@ -168,12 +168,12 @@ class ShardedPagedServer(PagedServer):
             return {s: one(s) for s in (False, True)}
 
         self._chunk_step = mk(
-            _paged_chunk_step,
+            _paged_chunk_step, "chunk",
             (specs["params"], specs["kv"], specs["kv_scales"],
              specs["lane2"], specs["lane"], specs["lane"], specs["lane2"],
              specs["lane"], specs["lane"]) + sampling_specs, out_specs)
         self._decode_step = mk(
-            _paged_decode_step,
+            _paged_decode_step, "decode",
             (specs["params"], specs["kv"], specs["kv_scales"],
              specs["lane2"], specs["lane"], specs["lane"],
              specs["lane"]) + sampling_specs, out_specs)
@@ -182,7 +182,7 @@ class ShardedPagedServer(PagedServer):
             # drafts/verdicts shard their lane dim over `cluster`, the
             # acceptance count is computed shard-locally per lane group
             self._spec_step = mk(
-                _paged_spec_step,
+                _paged_spec_step, "spec",
                 (specs["params"], specs["kv"], specs["kv_scales"],
                  specs["lane2"], specs["lane"], specs["lane"], specs["lane"],
                  specs["lane2"], specs["lane"]) + sampling_specs,
@@ -260,10 +260,12 @@ class ShardedPagedServer(PagedServer):
                 victim = self._victim(head)
                 if victim is None:
                     break
-                self._preempt(victim)
+                with self.tracer.span("preempt", rid=victim.rid):
+                    self._preempt(victim)
                 continue
             self.queue.remove(head)
-            self._place(head, best[1], best[2])
+            with self.tracer.span("place", rid=head.rid):
+                self._place(head, best[1], best[2])
 
     def _place(self, req: SeqState, lane: int, plan: dict):
         self.cpool.place(req.rid, plan["cluster"])
