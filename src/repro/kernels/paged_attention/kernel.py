@@ -13,11 +13,11 @@ One kernel body serves two entry points:
 ``paged_prefill_fwd``
     A whole prompt chunk (``C`` tokens) per request against the paged pool,
     flash-style.  Grid ``(B, ceil(n_pages / G))``: each step attends ``G``
-    KV pages (``pages_per_step``) with a single online-softmax rescale plus
-    the causal in-chunk mask (the chunk's own K/V are pool-resident by the
-    time the kernel runs, so one mask covers both history and in-chunk
-    causality).  q enters laid out per KV head, ``(B, Kv, C*G, hd)``, and
-    the step loops over the KV heads, so every product is a 2-D dot.
+    KV pages with a single online-softmax rescale plus the causal in-chunk
+    mask (the chunk's own K/V are pool-resident by the time the kernel
+    runs, so one mask covers both history and in-chunk causality).  q
+    enters laid out per KV head, ``(B, Kv, C*G, hd)``, and the step loops
+    over the KV heads, so every product is a 2-D dot.
 
 ``paged_decode_fwd``
     One query token per request — the C=1 special case of the above (with
@@ -25,16 +25,29 @@ One kernel body serves two entry points:
     point for the engine's decode path.
 
 Both take a *fused* KV pool of shape ``(P, 2, page, Kv, hd)`` — K and V for
-a page live in one block and are fetched through one combined index map,
-halving the address-translation work of the old separate-K/V layout.
+a page live in one block and are fetched together — and a *repeat-padded*
+block table: entries past a request's last mapped page hold the last
+mapped physical page (never -1).  A scalar-prefetched per-request page
+count (``page_counts``) gates the compute.  ``ops.pad_block_table``
+produces the padded form from a -1-marked table.
 
-Both require a *repeat-padded* block table: entries past a request's last
-mapped page hold the last mapped physical page (never -1).  Trailing grid
-steps therefore map to the same block as their predecessor, which lets the
-Pallas pipeline elide the DMA entirely, and a scalar-prefetched per-request
-page count (``page_counts``) gates the compute, so fully-unmapped trailing
-steps cost neither fetch nor FLOPs.  ``ops.pad_block_table`` produces the
-padded form from a -1-marked table.
+Pages per grid step.  ``G`` follows the shapes: ``kv_pages_per_step(page,
+n_pages)``, ``KV_TOKENS_PER_STEP`` tokens' worth (one 128-lane row of
+scores per query row), capped at the table's width; ``pages_per_step``
+overrides it.  The grid walks every step of every lane, and the table is
+as wide as the longest request, so most steps hold no page of their lane;
+what such a step costs is the grid's per-step overhead, so the kernel's
+time is counted in grid steps, and ``G`` sets how many there are.
+
+Fetching.  Where a DMA can take one page out of the pool's HBM tiling
+(``_page_dma_aligned``: bf16 pools of 2, 4, 8 or 16 KV heads among them),
+the pool stays in HBM and the kernel
+copies a step's pages itself into a double buffer, the next step's pages
+in flight while this one computes: only the pages the lane has, so a step
+past them costs neither fetch nor FLOPs, nor any work per page.  Otherwise
+the ``G`` pages come as ``G`` pipelined block operands, whose index maps
+repeat the last page past a lane's end (the pipeline then elides the
+DMA), at a fixed bookkeeping cost per operand per grid step.
 """
 from __future__ import annotations
 
@@ -48,9 +61,29 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+#: KV tokens one grid step attends: one 128-lane row of scores per query
+#: row (16 pages of 8 tokens)
+KV_TOKENS_PER_STEP = 128
+
 
 def _cdiv(a: int, b: int) -> int:
     return -(-a // b)
+
+
+def _page_dma_aligned(dtype, kv_heads: int) -> bool:
+    """Whether a DMA can take one page, ``(2, page, Kv, hd)``, out of the
+    pool in HBM: XLA tiles its ``(Kv, hd)`` rows in groups of a power of
+    two of at least the dtype's packing (f32 1, bf16 2, int8 4 rows per
+    32-bit sublane), up to 8 sublanes, and Mosaic slices only whole tiles."""
+    pack = max(1, 4 // jnp.dtype(dtype).itemsize)
+    pow2 = kv_heads & (kv_heads - 1) == 0
+    return (pow2 and kv_heads >= pack) or kv_heads % (8 * pack) == 0
+
+
+def kv_pages_per_step(page_size: int, n_pages: int) -> int:
+    """The pages one grid step fetches: ``KV_TOKENS_PER_STEP`` tokens'
+    worth, at least one page and at most the block table's width."""
+    return min(n_pages, max(1, KV_TOKENS_PER_STEP // page_size))
 
 
 # ===========================================================================
@@ -61,7 +94,8 @@ def _cdiv(a: int, b: int) -> int:
                                              "scale"))
 def paged_decode_fwd(q: jax.Array, kv_pages: jax.Array,
                      block_table: jax.Array, page_counts: jax.Array,
-                     lengths: jax.Array, *, pages_per_step: int = 2,
+                     lengths: jax.Array, *,
+                     pages_per_step: int | None = None,
                      scale: float | None = None,
                      interpret: bool = False,
                      kv_scales: jax.Array | None = None) -> jax.Array:
@@ -70,7 +104,8 @@ def paged_decode_fwd(q: jax.Array, kv_pages: jax.Array,
     -1; see module docstring); page_counts: (B,) mapped logical pages per
     request; lengths: (B,) tokens per request; kv_scales: optional
     (P, 2, Kv) float32 per-page dequantization scales for an int8 pool
-    (see ``paged_prefill_fwd``).
+    (see ``paged_prefill_fwd``); pages_per_step: pages per grid step,
+    None for ``kv_pages_per_step`` of the shapes.
 
     Decode is exactly the C=1 case of chunked prefill: with
     ``q_start = lengths - 1`` the prefill mask ``tok < len & tok <= qpos``
@@ -91,14 +126,62 @@ def paged_decode_fwd(q: jax.Array, kv_pages: jax.Array,
 
 def _prefill_kernel(bt_ref, cnt_ref, len_ref, start_ref, q_ref, *refs,
                     scale: float, page_size: int, g_pages: int, groups: int,
-                    kv_heads: int, quant: bool):
-    kv_refs = refs[:g_pages]
-    rest = refs[g_pages:]
-    sc_refs = rest[:g_pages] if quant else ()
-    rest = rest[g_pages:] if quant else rest
-    o_ref = rest[0]
-    m_ref, l_ref, acc_ref = rest[1:]
+                    kv_heads: int, quant: bool, manual: bool):
+    # manual: the pool (and its scale rows) in HBM, a step's pages copied
+    # by the kernel into a double buffer; else g pipelined page operands
+    n_src = 1 if manual else g_pages
+    kv_src, refs = refs[:n_src], refs[n_src:]
+    sc_src, refs = (refs[:n_src], refs[n_src:]) if quant else ((), refs)
+    o_ref, *scratch = refs
+    m_ref, l_ref, acc_ref = scratch[-3:]
     b, j = pl.program_id(0), pl.program_id(1)
+    npages = cnt_ref[b]
+
+    if manual:
+        kv_buf, sem = scratch[0], scratch[-4]
+        sc_buf = scratch[1] if quant else None
+
+        def page_copies(step, slot):
+            # (live, copies) per page of a step: logical page step*g + gi
+            # of the lane into buffer slot ``slot``, live below the count
+            out = []
+            for gi in range(g_pages):
+                lp = step * g_pages + gi
+                page = bt_ref[b, jnp.minimum(lp, npages - 1)]
+                cps = [pltpu.make_async_copy(
+                    kv_src[0].at[page], kv_buf.at[slot, gi], sem.at[slot])]
+                if quant:
+                    cps.append(pltpu.make_async_copy(
+                        sc_src[0].at[page], sc_buf.at[slot, gi],
+                        sem.at[slot]))
+                out.append((lp < npages, cps))
+            return out
+
+        def each(step, slot, op):
+            for live, cps in page_copies(step, slot):
+                @pl.when(live)
+                def _(cps=cps):
+                    for c in cps:
+                        getattr(c, op)()
+
+        def start(step, slot):
+            each(step, slot, "start")
+
+        def wait(step, slot):
+            each(step, slot, "wait")
+
+        @pl.when((b == 0) & (j == 0))
+        def _clear():
+            # past a lane's last page a buffer holds what an earlier step
+            # fetched there (masked); clear what none has, so no NaN left
+            # in VMEM reaches p @ V
+            kv_buf[...] = jnp.zeros_like(kv_buf)
+            if quant:
+                sc_buf[...] = jnp.zeros_like(sc_buf)
+
+        @pl.when((j == 0) & (npages > 0))
+        def _first():
+            start(0, 0)
 
     @pl.when(j == 0)
     def _init():
@@ -106,10 +189,28 @@ def _prefill_kernel(bt_ref, cnt_ref, len_ref, start_ref, q_ref, *refs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    npages = cnt_ref[b]
-
     @pl.when(j * g_pages < npages)
     def _accumulate():
+        if manual:
+            slot = j % 2
+
+            @pl.when((j + 1) * g_pages < npages)
+            def _next():
+                start(j + 1, 1 - slot)  # the next step's pages in flight
+            wait(j, slot)               # while this step computes
+
+            def rows(gi, kv, h):
+                return kv_buf[slot, gi, kv, :, h, :]
+
+            def page_scale(gi, i):
+                return sc_buf[slot, gi, 0, i]
+        else:
+            def rows(gi, kv, h):
+                return kv_src[gi][0, kv, :, h, :]
+
+            def page_scale(gi, i):
+                return sc_src[gi][0, 0, i]
+
         # q rows are (chunk token c, group member g) pairs of one kv head,
         # row r = c * groups + g, so the query position is start + r // G;
         # the causal test tok <= start + r // G is rewritten without the
@@ -126,10 +227,10 @@ def _prefill_kernel(bt_ref, cnt_ref, len_ref, start_ref, q_ref, *refs,
             # (T, hd) rows of kv head h: one strided read per fetched page;
             # an int8 page is dequantized by its (page, K/V, head) scale
             parts = []
-            for gi, r in enumerate(kv_refs):
-                x = r[0, kv, :, h, :].astype(jnp.float32)
+            for gi in range(g_pages):
+                x = rows(gi, kv, h).astype(jnp.float32)
                 if quant:
-                    x = x * sc_refs[gi][0, kv, h]
+                    x = x * page_scale(gi, kv * kv_heads + h)
                 parts.append(x)
             return jnp.concatenate(parts, axis=0)
 
@@ -164,7 +265,8 @@ def _prefill_kernel(bt_ref, cnt_ref, len_ref, start_ref, q_ref, *refs,
 def paged_prefill_fwd(q: jax.Array, kv_pages: jax.Array,
                       block_table: jax.Array, page_counts: jax.Array,
                       lengths: jax.Array, q_start: jax.Array, *,
-                      pages_per_step: int = 2, scale: float | None = None,
+                      pages_per_step: int | None = None,
+                      scale: float | None = None,
                       interpret: bool = False,
                       kv_scales: jax.Array | None = None) -> jax.Array:
     """q: (B,C,H,hd) — a chunk of C query tokens per request, occupying
@@ -175,15 +277,15 @@ def paged_prefill_fwd(q: jax.Array, kv_pages: jax.Array,
 
     When ``kv_scales`` is given — (P, 2, Kv) float32, one scale per (page,
     K/V, kv-head) — the pool is int8 and each fetched page block is
-    dequantized in-kernel before the attention math; the scale blocks ride
-    the same page-indexed DMA as their K/V pages, so the extra traffic is
-    4 bytes per (K/V, head) per page.
+    dequantized in-kernel before the attention math; each page's scales
+    travel beside it as one lane-aligned row of 128 floats.
 
     Returns (B,C,H,hd)."""
     B, C, H, hd = q.shape
     P, _, page, Kv, _ = kv_pages.shape
     n_pages = block_table.shape[1]
-    g = max(1, min(pages_per_step, n_pages))
+    g = kv_pages_per_step(page, n_pages) if pages_per_step is None \
+        else max(1, min(pages_per_step, n_pages))
     n_steps = _cdiv(n_pages, g)
     groups = H // Kv
     R = C * groups
@@ -194,44 +296,53 @@ def paged_prefill_fwd(q: jax.Array, kv_pages: jax.Array,
     qh = q.reshape(B, C, Kv, groups, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(B, Kv, R, hd)
 
-    def page_imap(gi):
-        def imap(b, j, bt, cnt, ln, st):
-            idx = jnp.minimum(j * g + gi, n_pages - 1)
-            return bt[b, idx]
-        return imap
-
-    def kv_spec(gi):
-        im = page_imap(gi)
-        return pl.BlockSpec((1, 2, page, Kv, hd),
-                            lambda b, j, *a, _im=im: (_im(b, j, *a), 0, 0, 0, 0))
-
-    def sc_spec(gi):
-        im = page_imap(gi)
-        return pl.BlockSpec((1, 2, Kv),
-                            lambda b, j, *a, _im=im: (_im(b, j, *a), 0, 0))
-
     q_spec = pl.BlockSpec((1, Kv, R, hd), lambda b, j, *_: (b, 0, 0, 0))
-    in_specs = [q_spec] + [kv_spec(gi) for gi in range(g)]
-    operands = [qh] + [kv_pages] * g
+    sources = [kv_pages]
     if quant:
-        in_specs += [sc_spec(gi) for gi in range(g)]
-        operands += [kv_scales] * g
+        # one lane-aligned row of scales per page (K's heads, then V's): a
+        # page's (2, Kv) scale block is not a slice a DMA can take
+        width = _cdiv(2 * Kv, 128) * 128
+        sources.append(jnp.pad(kv_scales.reshape(P, 1, 2 * Kv),
+                               ((0, 0), (0, 0), (0, width - 2 * Kv))))
+    scratch = [pltpu.VMEM((Kv, R, 1), jnp.float32),
+               pltpu.VMEM((Kv, R, 1), jnp.float32),
+               pltpu.VMEM((Kv, R, hd), jnp.float32)]
+    # the kernel copies a step's pages itself where a DMA can take a page
+    # out of the pool's tiling, else they come as g pipelined operands
+    manual = _page_dma_aligned(kv_pages.dtype, Kv)
+    if manual:
+        hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
+        in_specs = [q_spec] + [hbm] * len(sources)
+        operands = [qh] + sources
+        scratch = [pltpu.VMEM((2, g, 2, page, Kv, hd), kv_pages.dtype)] + \
+            ([pltpu.VMEM((2, g, 1, width), jnp.float32)] if quant else []) + \
+            [pltpu.SemaphoreType.DMA((2,))] + scratch
+    else:
+        def page_spec(gi, block):
+            # past its lane's last page an operand resolves to that page
+            # (the table is repeat-padded), so the block repeats and the
+            # pipeline elides the DMA
+            def imap(b, j, bt, *_):
+                page_id = bt[b, jnp.minimum(j * g + gi, n_pages - 1)]
+                return (page_id,) + (0,) * (len(block) - 1)
+            return pl.BlockSpec(block, imap)
+
+        blocks = [(1, 2, page, Kv, hd)] + ([(1, 1, width)] if quant else [])
+        in_specs = [q_spec] + [page_spec(gi, blk) for blk in blocks
+                               for gi in range(g)]
+        operands = [qh] + [src for src in sources for _ in range(g)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, n_steps),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[
-            pltpu.VMEM((Kv, R, 1), jnp.float32),
-            pltpu.VMEM((Kv, R, 1), jnp.float32),
-            pltpu.VMEM((Kv, R, hd), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(_prefill_kernel, scale=sc, page_size=page,
                           g_pages=g, groups=groups, kv_heads=Kv,
-                          quant=quant),
+                          quant=quant, manual=manual),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Kv, R, hd), q.dtype),
         interpret=interpret,

@@ -84,7 +84,7 @@ def pad_block_table(block_table: jax.Array, page_counts: jax.Array
 
 
 def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
-                    interpret=None, pages_per_step: int = 2):
+                    interpret=None, pages_per_step: int | None = None):
     """Decode fast path, host-friendly form.
 
     q: (B,H,hd); k/v_pages: (P, page, Kv, hd); block_table: (B, max_pages)
@@ -100,7 +100,7 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
 
 
 def paged_prefill(q, k_pages, v_pages, block_table, lengths, q_start, *,
-                  interpret=None, pages_per_step: int = 2):
+                  interpret=None, pages_per_step: int | None = None):
     """Chunked-prefill fast path, host-friendly form.
 
     q: (B,C,H,hd) — C chunk tokens at positions q_start..q_start+C-1, whose
@@ -114,7 +114,7 @@ def paged_prefill(q, k_pages, v_pages, block_table, lengths, q_start, *,
 
 
 def paged_decode_fused(q, kv_pages, block_table, page_counts, lengths, *,
-                       interpret=None, pages_per_step: int = 2,
+                       interpret=None, pages_per_step: int | None = None,
                        kv_scales=None):
     """Decode on a fused pool + repeat-padded device block table.  Pass
     ``kv_scales`` ((P, 2, Kv) f32) for an int8 pool — dequant happens
@@ -125,8 +125,8 @@ def paged_decode_fused(q, kv_pages, block_table, page_counts, lengths, *,
 
 
 def paged_prefill_fused(q, kv_pages, block_table, page_counts, lengths,
-                        q_start, *, interpret=None, pages_per_step: int = 2,
-                        kv_scales=None):
+                        q_start, *, interpret=None,
+                        pages_per_step: int | None = None, kv_scales=None):
     """Chunked prefill on a fused pool + repeat-padded device block table.
     ``kv_scales`` as in ``paged_decode_fused``."""
     return K.paged_prefill_fwd(q, kv_pages, block_table, page_counts,

@@ -301,7 +301,10 @@ class EngineConfig:
     #                                     historical prefill interleave)
     # kernels
     use_kernel: bool = True
-    pages_per_step: int = 2
+    pages_per_step: Optional[int] = None    # pages per paged-kernel grid
+    #                                     step; None -> derived from the
+    #                                     page size and max_pages_per_seq
+    #                                     (kernel.kv_pages_per_step)
     # speculation
     spec_k: int = 0
     drafter: Optional[object] = None    # runtime.speculative.Drafter

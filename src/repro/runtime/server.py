@@ -332,7 +332,8 @@ class PagedServer:
         self.pool = PagedKVPool(num_pages, self.page_size, self.max_pages,
                                 self.rab)
 
-    def _build_device_state(self, num_pages: int, pages_per_step: int):
+    def _build_device_state(self, num_pages: int,
+                            pages_per_step: Optional[int]):
         cfg = self.cfg
         L_, kv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim
         dt = jnp.int8 if self.quant_kv else jnp.dtype(cfg.param_dtype)
@@ -1684,7 +1685,7 @@ def _sample_tokens(logits, seeds, pos, temps, top_ks, top_ps):
 
 
 def _paged_forward(cfg: ArchConfig, use_kernel: bool,
-                   pages_per_step: int, interpret: bool,
+                   pages_per_step: Optional[int], interpret: bool,
                    num_pages: int, params, kv_pages, kv_scales, bt, lens,
                    n_new, feed, last_tok, use_last, *, axis_name=None,
                    quant=False):
@@ -1772,7 +1773,8 @@ def _paged_forward(cfg: ArchConfig, use_kernel: bool,
     return logits, kv_pages, kv_scales
 
 
-def _paged_chunk_step(cfg: ArchConfig, use_kernel: bool, pages_per_step: int,
+def _paged_chunk_step(cfg: ArchConfig, use_kernel: bool,
+                      pages_per_step: Optional[int],
                       interpret: bool, num_pages: int, params, kv_pages,
                       kv_scales, bt, lens, n_new, feed, last_tok, use_last,
                       seeds, temps, top_ks, top_ps, *, axis_name=None,
@@ -1804,7 +1806,8 @@ def _paged_chunk_step(cfg: ArchConfig, use_kernel: bool, pages_per_step: int,
     return nxt, kv_pages, kv_scales, lens + n_new
 
 
-def _paged_spec_step(cfg: ArchConfig, use_kernel: bool, pages_per_step: int,
+def _paged_spec_step(cfg: ArchConfig, use_kernel: bool,
+                     pages_per_step: Optional[int],
                      interpret: bool, num_pages: int, params, kv_pages,
                      kv_scales, bt, lens, active, last_tok, drafts, n_spec,
                      seeds, temps, top_ks, top_ps, *, axis_name=None,
@@ -1855,7 +1858,8 @@ def _paged_spec_step(cfg: ArchConfig, use_kernel: bool, pages_per_step: int,
     return verdict, kv_pages, kv_scales, last, new_lens
 
 
-def _paged_decode_step(cfg: ArchConfig, use_kernel: bool, pages_per_step: int,
+def _paged_decode_step(cfg: ArchConfig, use_kernel: bool,
+                       pages_per_step: Optional[int],
                        interpret: bool, num_pages: int, params, kv_pages,
                        kv_scales, bt, lens, active, last_tok, seeds, temps,
                        top_ks, top_ps, *, axis_name=None, quant=False,

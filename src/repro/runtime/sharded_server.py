@@ -107,7 +107,8 @@ class ShardedPagedServer(PagedServer):
         self.rabs = self.cpool.rabs
         self.rab = self.rabs[0]
 
-    def _build_device_state(self, num_pages: int, pages_per_step: int):
+    def _build_device_state(self, num_pages: int,
+                            pages_per_step: Optional[int]):
         # the fused slab, re-laid-out: C contiguous (num_pages + 1) blocks
         # (trash page per cluster), pages sharded over `cluster`, kv heads
         # over `head`; lane state (incl. the sampling-policy rows) shards

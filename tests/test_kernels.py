@@ -12,6 +12,8 @@ from repro.kernels.paged_attention import (
     paged_attention, paged_attention_ref, paged_prefill, paged_prefill_ref,
     paged_prefill_fused, pad_block_table, page_counts_for,
 )
+from repro.kernels.paged_attention.kernel import kv_pages_per_step
+from repro.optim.compress import headwise_scales, quantize_int8
 
 TOL = {jnp.float32: dict(rtol=2e-4, atol=2e-4),
        jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
@@ -153,7 +155,13 @@ def _paged_pool(rng, B, Kv, hd, page, npg, P, lengths):
     return kp, vp, jnp.asarray(bt)
 
 
-@pytest.mark.parametrize("pages_per_step", [1, 2, 3])
+# pages per grid step: one and two pages, the value the kernel derives from
+# the shapes (None), and one wider than the block table (clamped to it)
+WIDE = 99
+PAGES_PER_STEP = [1, 2, None, WIDE]
+
+
+@pytest.mark.parametrize("pages_per_step", [1, 2, 3, None, WIDE])
 def test_paged_decode_multi_page_grid(pages_per_step, rng):
     """Multi-page decode grid is numerically identical to the oracle for
     every pages-per-step grouping (incl. groups that don't divide npg)."""
@@ -173,7 +181,7 @@ def test_paged_decode_multi_page_grid(pages_per_step, rng):
     (16, 8, 2, 16, 8, 6, 32),      # G=4
     (5, 6, 6, 32, 16, 3, 8),       # G=1, chunk not a divisor of anything
 ])
-@pytest.mark.parametrize("pages_per_step", [1, 2])
+@pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)
 def test_paged_prefill_matches_ref(C, H, Kv, hd, page, npg, P,
                                    pages_per_step, rng):
     """Chunked prefill vs the dense oracle across random prompt lengths,
@@ -194,7 +202,7 @@ def test_paged_prefill_matches_ref(C, H, Kv, hd, page, npg, P,
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("pages_per_step", [1, 2])
+@pytest.mark.parametrize("pages_per_step", PAGES_PER_STEP)
 def test_paged_prefill_fused_aliased_pages(pages_per_step, rng):
     """Shared-prefix block tables alias the same physical pages across
     lanes (prefix-cache hits); the fused kernel must match the oracle when
@@ -224,6 +232,50 @@ def test_paged_prefill_fused_aliased_pages(pages_per_step, rng):
                             jnp.asarray(start))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("H,Kv", [(32, 4), (32, 8)])
+def test_paged_prefill_fused_at_cell_head_groupings(H, Kv, kv_dtype, rng):
+    """The benchmark cells' GQA groupings (yi-6b: 32 heads over 4 KV heads,
+    Mistral-NeMo-12B: over 8) at a small head dim, through the engine's
+    fused entry at the derived pages per step (16 pages of 8 tokens): a
+    lane over two grid steps, one inside its first page, one empty."""
+    B, C, hd, page, npg, P = 3, 4, 16, 8, 20, 48
+    lengths = np.array([150, 5, 0], np.int32)
+    start = np.maximum(lengths - C, 0).astype(np.int32)
+    q = jax.random.normal(rng, (B, C, H, hd), jnp.float32) * 0.3
+    kp, vp, bt = _paged_pool(rng, B, Kv, hd, page, npg, P, lengths)
+    counts = page_counts_for(jnp.asarray(lengths), page)
+    pool = jnp.stack([kp, vp], axis=1)               # (P, 2, page, Kv, hd)
+    scales, ref_scales = None, {}
+    if kv_dtype == "int8":
+        scales = headwise_scales(pool.transpose(0, 1, 3, 2, 4)
+                                 .reshape(P, 2, Kv, page * hd))  # (P,2,Kv)
+        pool = quantize_int8(pool, scales[:, :, None, :, None])
+        ref_scales = dict(k_scales=scales[:, 0], v_scales=scales[:, 1])
+    else:
+        pool = pool.astype(jnp.bfloat16)
+    assert kv_pages_per_step(page, npg) == 16
+    out = paged_prefill_fused(
+        q, pool, pad_block_table(bt, counts), counts, jnp.asarray(lengths),
+        jnp.asarray(start), interpret=True, kv_scales=scales)
+    ref = paged_prefill_ref(q, pool[:, 0], pool[:, 1], bt,
+                            jnp.asarray(lengths), jnp.asarray(start),
+                            **ref_scales)
+    live = lengths > 0
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(ref)[live],
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("page,width,want", [
+    (8, 320, 16),       # the benchmark cells: 128 tokens per grid step
+    (8, 2, 2),          # clamped to the block table's width
+    (128, 320, 1),      # a page of 128 tokens or more: one page a step
+    (256, 320, 1),
+])
+def test_kv_pages_per_step(page, width, want):
+    assert kv_pages_per_step(page, width) == want
 
 
 def test_paged_prefill_matches_token_by_token(rng):
