@@ -10,7 +10,7 @@ import sys
 from collections import Counter
 from typing import Optional
 
-from bench.lib import check, flops, serve
+from bench.lib import check, serve
 from bench.lib.record import FAILED, Run
 
 
@@ -37,29 +37,31 @@ def run_cell(h, cell_name: str, *, seed: int, seconds: float, trace: bool,
     import jax
     from repro.runtime import make_engine
     from bench.lib.compile_log import CompileLog
-    from bench.lib.model import arch_config, draw_params, engine_config
+    from bench.lib.model import draw_params, engine_config
 
     cell = h.cell(cell_name)
     conf = h.config(cell["config"])
+    model = h.model(conf)
     mix = h.traffic(cell["traffic"])
     gen = h.generator(mix)
     compiles = CompileLog()
     dev = jax.devices()[0]
-    cfg = arch_config(conf)
-    ecfg = engine_config(conf, gen.max_len(mix),
+    cfg = model.arch_config(conf)
+    shapes = model.shapes(conf)
+    ecfg = engine_config(conf, model, gen.max_len(mix),
                          use_kernel=dev.platform == "tpu")
     plan = gen.generate(mix, vocab=cfg.vocab_size, seed=seed,
                         seconds=seconds)
-    params = draw_params(cfg, seed)
+    params = draw_params(cfg, seed, model.FAN_IN)
     engine = make_engine(cfg, params, ecfg)
     if fault is not None:
         fault(engine)
     c = ecfg.cache
-    log(f"settings: {cfg.name} L={cfg.num_layers} d={cfg.d_model} "
-        f"H={cfg.num_heads}/{cfg.num_kv_heads} hd={cfg.resolved_head_dim} "
-        f"ff={cfg.d_ff} V={cfg.vocab_size} {cfg.param_dtype}; lanes "
-        f"{ecfg.max_lanes}, pool {c.num_pages} pages of {c.page_size} "
-        f"tokens ({c.num_pages * c.page_size} tokens), max_pages_per_seq "
+    sizes = " ".join(f"{k}={v}" for k, v in shapes.items())
+    log(f"settings: {cfg.name} ({conf['model']}) {sizes} "
+        f"{cfg.param_dtype}; lanes {ecfg.max_lanes}, pool {c.num_pages} "
+        f"pages of {c.page_size} tokens ({c.num_pages * c.page_size} "
+        f"tokens), max_pages_per_seq "
         f"{c.max_pages_per_seq}, chunk {ecfg.chunk}, pages_per_step "
         f"{ecfg.pages_per_step}, prefix cache {c.enable_prefix_cache}, "
         f"kernel {engine.use_kernel}")
@@ -91,7 +93,7 @@ def run_cell(h, cell_name: str, *, seed: int, seconds: float, trace: bool,
     gc.collect()
     ref = h.reference(conf)
     picked = check.sample(out["requests"], out["end"], seed)
-    weights = draw_params(cfg, seed) if picked else None
+    weights = draw_params(cfg, seed, model.FAN_IN) if picked else None
     t_ref = serve.clock()
     gaps, low = check.compare(ref, weights, conf, picked, lower=lower)
     del weights
@@ -104,7 +106,7 @@ def run_cell(h, cell_name: str, *, seed: int, seconds: float, trace: bool,
 
     run = Run(start=out["start"], end=out["end"], requests=out["requests"],
               steps=out["steps"], setup_s=out["start"] - t_start,
-              shapes=flops.model_shapes(conf), peaks=peaks,
+              shapes=shapes, model=model, peaks=peaks,
               cache_start=out["cache_start"], cache_end=out["cache_end"])
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": cell["chips"], "memory_peak_bytes": peak}

@@ -7,6 +7,11 @@ means adding files and entries, never editing one:
     bench/configs/<config>.json       a model configuration, as run
     bench/traffic/<mix>.json          a traffic mix's parameters; its
                                       ``kind`` names bench/generators/<kind>.py
+    bench/models/<model>.py           the architecture a configuration
+                                      names under ``model``: the program's
+                                      ``ArchConfig``, the weights' draw
+                                      rule ``FAN_IN``, ``shapes(conf)``
+                                      and the counts over them
     bench/references/<reference>.py   the plain reference a configuration
                                       names under ``reference``
     bench/metrics/<metric>.py         one metric's reader, ``read(run)``
@@ -86,6 +91,20 @@ class Harness:
 
     def generator(self, mix: dict):
         return self._module("generators", mix["kind"])
+
+    def model(self, conf: dict):
+        """The configuration's model module, the only code that knows its
+        architecture's sizes.  There is no default: a configuration
+        that names none, or one that is missing, is refused with the
+        modules there are."""
+        known = sorted(p.stem for p in (self.bench_dir / "models").glob(
+            "*.py"))
+        name = conf.get("model")
+        if name not in known:
+            raise KeyError(
+                f"config {conf['name']!r} names model module {name!r}; "
+                f"known (bench/models/<model>.py): {known}")
+        return self._module("models", name)
 
     def reference(self, conf: dict):
         return self._module("references", conf["reference"])

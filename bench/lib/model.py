@@ -1,6 +1,7 @@
-"""The system under test, built from a configuration file: the program's
-architecture spec, its engine settings, and random weights drawn by the
-benchmark itself from the seed.
+"""The system under test, built from a configuration file and its model
+module (bench/models/<model>.py, which gives the program's architecture
+spec and the weights' draw rule): its engine settings, and random weights
+drawn by the benchmark itself from the seed.
 
 The benchmark, not the program, makes the weights, in one jitted call on
 the device and in the type they are served in.  The reference draws them
@@ -15,44 +16,18 @@ import math
 
 import numpy as np
 
-from bench.lib.flops import kv_bytes_per_token, model_shapes
 
-#: the fan-in of each weight, by leaf name, from its stacked shape
-_FAN_IN = {
-    "wq": lambda s: s[-3], "wk": lambda s: s[-3], "wv": lambda s: s[-3],
-    "wo": lambda s: s[-3] * s[-2],
-    "w_gate": lambda s: s[-2], "w_up": lambda s: s[-2],
-    "w_down": lambda s: s[-2],
-    "head": lambda s: s[-1],
-}
-
-
-def arch_config(conf: dict):
-    """The program's ``ArchConfig`` for a llama-family configuration."""
-    from repro.configs.base import ArchConfig
-
-    if conf.get("hidden_act", "silu") != "silu":
-        raise ValueError(f"{conf['name']}: only SwiGLU (silu) MLPs run")
-    s = model_shapes(conf)
-    return ArchConfig(
-        name=conf["name"], family="dense", num_layers=s["L"],
-        d_model=s["d"], num_heads=s["H"], num_kv_heads=s["Kv"],
-        d_ff=s["ff"], vocab_size=s["V"], head_dim=s["hd"],
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=s["tied"], param_dtype=conf["torch_dtype"],
-        compute_dtype=conf["torch_dtype"])
-
-
-def engine_config(conf: dict, max_len: int, *, use_kernel: bool):
+def engine_config(conf: dict, model, max_len: int, *, use_kernel: bool):
     """Engine settings: the configuration's lanes and KV pool (its byte
-    budget over the engine's own page size); page size, pages per kernel
-    step and chunk stay at ``EngineConfig``'s defaults."""
+    budget over the engine's own page size, at the bytes a token's cache
+    takes in ``model``, the configuration's model module); page size,
+    pages per kernel step and chunk stay at ``EngineConfig``'s
+    defaults."""
     from repro.runtime import CacheConfig, EngineConfig
 
     serving = conf["serving"]
     page = EngineConfig().cache.page_size
-    page_bytes = kv_bytes_per_token(model_shapes(conf)) * page
+    page_bytes = model.kv_bytes_per_token(model.shapes(conf)) * page
     return EngineConfig(
         cache=CacheConfig(
             num_pages=int(serving["kv_pool_bytes"] // page_bytes),
@@ -69,24 +44,24 @@ def seed_key(seed: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _drawer(cfg):
-    """One jitted program that draws every weight of ``cfg``."""
+def _drawer(cfg, rule):
+    """One jitted program that draws every weight of ``cfg`` by ``rule``
+    (the items of a model module's ``FAN_IN``)."""
     import jax
     import jax.numpy as jnp
     from repro.models import model as M
 
+    fan_in = dict(rule)
     flat, tree = jax.tree_util.tree_flatten_with_path(M.param_specs(cfg))
+    missing = [jax.tree_util.keystr(path) for path, _ in flat
+               if path[-1].key not in fan_in]
+    if missing:
+        raise ValueError(f"{cfg.name}: no draw rule for weights {missing}")
     plan = []
     for path, sds in flat:
-        leaf = path[-1].key
-        if leaf == "scale":
-            plan.append((None, sds))
-        elif leaf == "table":
-            plan.append((1.0, sds))
-        elif leaf in _FAN_IN:
-            plan.append((1.0 / math.sqrt(_FAN_IN[leaf](sds.shape)), sds))
-        else:
-            raise ValueError(f"no draw rule for weight {path}")
+        f = fan_in[path[-1].key]
+        plan.append((None if f is None else 1.0 / math.sqrt(f(sds.shape)),
+                     sds))
 
     @jax.jit
     def draw(key):
@@ -103,8 +78,10 @@ def _drawer(cfg):
     return draw, tree
 
 
-def draw_params(cfg, seed: int):
+def draw_params(cfg, seed: int, rule: dict):
     """The weights of ``cfg`` for ``seed``, on the default device, in the
-    tree the program's ``init_params`` would give."""
-    draw, tree = _drawer(cfg)
+    tree the program's ``init_params`` would give.  ``rule`` maps each
+    weight's leaf name to its fan-in from its shape, N(0, 1/fan_in), or
+    to None for ones; a leaf it does not name is an error."""
+    draw, tree = _drawer(cfg, tuple(rule.items()))
     return tree.unflatten(draw(seed_key(seed)))

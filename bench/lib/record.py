@@ -46,11 +46,12 @@ class Run:
     requests: List[RequestLog]
     steps: List[StepLog]
     setup_s: float
-    shapes: dict                # model sizes, see lib/flops.model_shapes
+    shapes: dict                # model sizes, from model.shapes(conf)
     peaks: dict                 # the device's row of peaks.json
     cache_start: dict = dataclasses.field(default_factory=dict)
     cache_end: dict = dataclasses.field(default_factory=dict)
     trace: Optional[object] = None      # lib/trace.Reduced, traced runs
+    model: Optional[object] = None      # bench/models/<model>.py: counts
 
     @property
     def seconds(self) -> float:

@@ -51,13 +51,15 @@ def main(argv=None) -> int:
     import jax
     from repro.runtime import make_engine
     from bench.lib import record, serve
-    from bench.lib.model import arch_config, draw_params, engine_config
+    from bench.lib.model import draw_params, engine_config
     conf = h.config(cell["config"])
+    model = h.model(conf)
     mix = h.traffic(cell["traffic"])
     gen = h.generator(mix)
-    cfg = arch_config(conf)
-    engine = make_engine(cfg, draw_params(cfg, args.seed), engine_config(
-        conf, gen.max_len(mix), use_kernel=True))
+    cfg = model.arch_config(conf)
+    engine = make_engine(
+        cfg, draw_params(cfg, args.seed, model.FAN_IN),
+        engine_config(conf, model, gen.max_len(mix), use_kernel=True))
     serve.warm_up(engine, cfg.vocab_size)
     rows = []
     for i, rate in enumerate(float(r) for r in args.rates.split(",")):

@@ -29,6 +29,8 @@ def test_spec_names_resolve_to_files():
         conf = h.config(w["config"])
         mix = h.traffic(w["traffic"])
         assert h.generator(mix).generate
+        model = h.model(conf)
+        assert model.arch_config and model.shapes and model.FAN_IN
         assert h.reference(conf).gaps
         assert conf["serving"]["chips"] == w["chips"]
     for m in h.spec["end_to_end"] + h.spec["per_layer"]:

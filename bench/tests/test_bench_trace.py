@@ -102,9 +102,10 @@ def test_roofline_and_decode_step_from_a_synthetic_trace():
         spans=[(T.WINDOW_SPAN, 100.0, 101.0),
                ("bench.step", 100.0, 100.04),
                ("bench.step", 100.2, 100.31)])
-    run = Run(start=0.5, end=2.0, requests=reqs, steps=steps, setup_s=0,
-              shapes=shapes, peaks=PEAKS, trace=tr)
     h = Harness()
+    run = Run(start=0.5, end=2.0, requests=reqs, steps=steps, setup_s=0,
+              shapes=shapes, peaks=PEAKS, trace=tr,
+              model=h.model({"name": "tiny", "model": "llama"}))
     kv = 2 * 2 * 2 * 16 * 2                 # L * (K, V) * Kv * hd * bytes
     nbytes = kv * (100 + 300) + 2 * (2 * 2 * 4 * 16 * 2)
     flops = 4 * (100 + 300) * 4 * 16 * 2

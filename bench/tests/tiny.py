@@ -1,6 +1,7 @@
 """A benchmark tree of CPU size for the tests: the real harness, generator,
-reference and metric readers, with one tiny llama-family configuration
-and one short traffic mix, written under a temporary directory."""
+model modules, reference and metric readers, with one tiny llama-family
+configuration and one short traffic mix, written under a temporary
+directory."""
 from __future__ import annotations
 
 import json
@@ -15,7 +16,8 @@ TINY_CONFIG = {
     "num_attention_heads": 4, "num_hidden_layers": 2,
     "num_key_value_heads": 2, "head_dim": 16, "rms_norm_eps": 1e-05,
     "rope_theta": 10000.0, "tie_word_embeddings": False,
-    "torch_dtype": "bfloat16", "vocab_size": 256, "reference": "llama",
+    "torch_dtype": "bfloat16", "vocab_size": 256, "model": "llama",
+    "reference": "llama",
     "serving": {"chips": 1, "max_lanes": 4, "kv_pool_bytes": 262144},
     "check": {"max_logit_gap": 0.04, "min_checked_tokens": 8},
 }
@@ -36,7 +38,7 @@ def make_tree(tmp: pathlib.Path, *, config=None, mix=None) -> pathlib.Path:
     the cell ``tiny-chat`` (config ``tiny``, traffic ``tiny-mix``)."""
     root = tmp / "root"
     bench = root / "bench"
-    for sub in ("lib", "generators", "references", "metrics"):
+    for sub in ("lib", "generators", "models", "references", "metrics"):
         shutil.copytree(BENCH / sub, bench / sub,
                         ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(BENCH / "peaks.json", bench / "peaks.json")
